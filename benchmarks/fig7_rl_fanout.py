@@ -92,5 +92,8 @@ def run() -> List[Row]:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         print(r.csv())

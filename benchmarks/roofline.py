@@ -209,5 +209,8 @@ def run(dryrun_json: Optional[str] = None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         print(r.csv())

@@ -50,17 +50,24 @@ def main() -> None:
     }
     selected = sys.argv[1:] or list(benches)
     print("name,us_per_call,derived")
+    failed = []
     for name in selected:
         t0 = time.time()
         try:
             rows = benches[name]()
         except Exception as exc:  # keep the harness going; record the failure
             print(f"{name}/ERROR,0.0,{type(exc).__name__}: {str(exc)[:160]}")
+            failed.append(name)
             continue
         for row in rows:
             print(row.csv())
         print(f"# {name} done in {time.time()-t0:.1f}s", flush=True)
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

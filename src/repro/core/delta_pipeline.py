@@ -338,10 +338,6 @@ class _KeyTask:
 class DeltaDumpPipeline:
     """Coordinates delta_encode dumps and delta_apply restores for one store."""
 
-    #: VMEM budget for the fused kernel's resident compaction buffer
-    #: (max_changed × chunk_bytes); past it the unfused two-kernel plan runs.
-    FUSED_VMEM_BYTES = 8 * 1024 * 1024
-
     def __init__(
         self,
         store: ChunkStore,
@@ -697,7 +693,7 @@ class DeltaDumpPipeline:
         # steps); the identical zero pad rows can never read as dirty
         K2 = 1 << (K - 1).bit_length()
         cap = self._capacity(K2)
-        if self.fused and cap * view.chunk_bytes <= self.FUSED_VMEM_BYTES:
+        if self.fused:
             return self._plan_device_fused(
                 key, view, pm, old_grid, new_grid, K, K2, cap, weight
             )
@@ -901,7 +897,7 @@ class DeltaDumpPipeline:
         K = part.n_local
         K2 = 1 << (K - 1).bit_length()
         cap = self._capacity(K2)
-        use_fused = self.fused and cap * view.chunk_bytes <= self.FUSED_VMEM_BYTES
+        use_fused = self.fused
         block_path = base_block_fn is not None and part.block_fn is not None
 
         def encode():

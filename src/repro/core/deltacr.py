@@ -42,6 +42,12 @@ import weakref
 from collections import OrderedDict
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
+# The same interpreter-shutdown hook concurrent.futures uses: runs *before*
+# non-daemon thread joins and daemon-thread teardown, so live workers drain
+# cleanly even when the process exits without shutdown() (an unhandled
+# exception while a dump is mid-device-fetch would otherwise kill the daemon
+# thread inside native code and abort).
+from threading import _register_atexit as _thread_atexit
 from typing import Any, Callable, Dict, List, Optional, Protocol, Set, Tuple, runtime_checkable
 
 import numpy as np
@@ -94,16 +100,6 @@ class _EitherEvent:
         return any(e.is_set() for e in self._events)
 
 
-try:
-    # The same interpreter-shutdown hook concurrent.futures uses: runs
-    # *before* non-daemon thread joins and daemon-thread teardown, so live
-    # workers drain cleanly even when the process exits without shutdown()
-    # (an unhandled exception while a dump is mid-device-fetch would
-    # otherwise kill the daemon thread inside native code and abort).
-    from threading import _register_atexit as _thread_atexit
-except ImportError:  # pragma: no cover - future interpreters
-    _thread_atexit = None
-
 _LIVE_WORKERS: "weakref.WeakSet[_SupervisedWorker]" = weakref.WeakSet()
 _WORKER_ATEXIT_ARMED = False
 
@@ -135,11 +131,10 @@ class _SupervisedWorker:
         self.deaths = 0              # worker threads that died mid-loop
         self.restarts = 0            # successor threads spawned
         global _WORKER_ATEXIT_ARMED
-        if _thread_atexit is not None:
-            _LIVE_WORKERS.add(self)
-            if not _WORKER_ATEXIT_ARMED:
-                _WORKER_ATEXIT_ARMED = True
-                _thread_atexit(_drain_workers_at_exit)
+        _LIVE_WORKERS.add(self)
+        if not _WORKER_ATEXIT_ARMED:
+            _WORKER_ATEXIT_ARMED = True
+            _thread_atexit(_drain_workers_at_exit)
         self._spawn(initial=True)
 
     def _spawn(self, *, initial: bool = False) -> None:

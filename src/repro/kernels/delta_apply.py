@@ -2,9 +2,15 @@
 
 The slow-path restore: a dump image arrives as (compacted dirty chunks,
 chunk indices); this kernel scatters them into the parent-generation tensor
-in place (donated base).  Index rows with ``idx == -1`` are padding from the
-fixed-capacity compaction and must not write — the grid step visits a
-sacrificial block and skips the store, leaving the aliased base intact.
+in place (aliased base).  Rows move as ``(lines, 128)`` blocks of their own
+dtype (``words.py``), one row (or one sub-block of a large row) per grid
+step.
+
+Index rows with ``idx == -1`` are padding from the fixed-capacity
+compaction and must not change the base.  A step writes its whole output
+block, so a padding step re-writes the data of the last valid row before
+it into that same row (the block stays resident, no extra traffic), and
+padding with no valid row before it copies base row 0 onto itself.
 """
 from __future__ import annotations
 
@@ -13,20 +19,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import words
+
 __all__ = ["delta_apply"]
 
 
-def _delta_apply_kernel(idx_ref, data_ref, base_ref, out_ref):
-    del base_ref
+def _delta_apply_kernel(src_ref, dst_ref, write_ref, data_ref, base_ref, out_ref):
+    del src_ref, dst_ref
     j = pl.program_id(0)
 
-    @pl.when(idx_ref[j] >= 0)
-    def _():
+    @pl.when(write_ref[j] > 0)
+    def _data():
         out_ref[...] = data_ref[...]
+
+    @pl.when(write_ref[j] == 0)
+    def _keep():
+        out_ref[...] = base_ref[...]
 
 
 def delta_apply(
-    base: jax.Array,     # (N, C) — donated
+    base: jax.Array,     # (N, C) — aliased to the output
     data: jax.Array,     # (M, C)
     idx: jax.Array,      # (M,) int32, -1 padding
     *,
@@ -34,24 +46,33 @@ def delta_apply(
 ) -> jax.Array:
     N, C = base.shape
     M = data.shape[0]
+    _, r, lines = words.block_plan(1, C, base.dtype)
+    bl = words.to_lines(base, lines)
+    dl = words.to_lines(data, lines)
 
-    def _safe(i, idx_ref):
-        v = idx_ref[i]
-        return jnp.where(v >= 0, v, 0)
+    idx = idx.astype(jnp.int32)
+    pos = jnp.arange(M, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(idx >= 0, pos, -1), axis=0)
+    write = (last >= 0).astype(jnp.int32)
+    src = jnp.maximum(last, 0)
+    dst = jnp.where(write > 0, idx[src], 0)
 
-    data_spec = pl.BlockSpec((1, C), lambda j, i: (j, 0))
-    base_spec = pl.BlockSpec((1, C), lambda j, i: (_safe(j, i), 0))
-    out_spec = pl.BlockSpec((1, C), lambda j, i: (_safe(j, i), 0))
+    blk = (1, r, words.LANES)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(M,),
-        in_specs=[data_spec, base_spec],
-        out_specs=out_spec,
+        num_scalar_prefetch=3,
+        grid=(M, lines // r),
+        in_specs=[
+            pl.BlockSpec(blk, lambda j, k, s, d, w: (s[j], k, 0)),
+            # base is read only before the first valid row, where dst is 0
+            pl.BlockSpec(blk, lambda j, k, s, d, w: (0, k, 0)),
+        ],
+        out_specs=pl.BlockSpec(blk, lambda j, k, s, d, w: (d[j], k, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _delta_apply_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(base.shape, base.dtype),
-        input_output_aliases={2: 0},  # base (3rd operand incl. scalar) -> out
+        out_shape=jax.ShapeDtypeStruct(bl.shape, bl.dtype),
+        input_output_aliases={4: 0},  # base (5th operand incl. scalars) -> out
         interpret=interpret,
-    )(idx.astype(jnp.int32), data, base)
+    )(src, dst, write, dl, bl)
+    return words.from_lines(out, C)

@@ -1,71 +1,104 @@
-"""Pallas TPU kernel: fused diff + compaction + checksum for delta dumps.
+"""Pallas TPU kernel: fused diff + checksum for delta dumps.
 
-The pre-fusion dump path touched every dirty byte three times: once in
+The pre-fusion dump path read every dirty byte three times: once in
 ``delta_diff`` (dirty bitmap), once in the compaction gather, and once for
-the integrity digest.  This kernel does all three in a single pass over the
-generation grids, so dirty bytes cross the memory hierarchy exactly once:
+the integrity checksums.  This kernel diffs old against new and computes
+the 4-lane checksums of ``ref.chunk_checksums_ref`` in the same pass over
+the grids, so the checksum costs no extra read; the compaction that
+follows is a gather of only the dirty rows (``max_changed`` of them), so
+the compaction buffer never has to live in VMEM.
 
-* per grid block, compare old vs new and reduce to a per-row dirty flag;
-* scatter each dirty row into the next free slot of a fixed-capacity
-  compaction buffer (ascending row order, deterministic — bit-identical to
-  ``ref.delta_compact_ref``);
-* emit 4-lane uint32 checksums of the row bytes in the same pass
-  (``ref.chunk_checksums_ref`` formulas) so the host can validate the
-  DMA'd bytes without re-reading the device grid.
-
-The grid walks blocks sequentially (TPU grid semantics), carrying the
-compaction cursor in the SMEM count output — revisited every step via a
-constant index_map, exactly the accumulation pattern the Pallas guide
-documents.  ``count`` totals ALL dirty rows, so ``count > max_changed``
-signals capacity overflow (the caller falls back to a full-grid dump).
-
-VMEM note: the compaction buffer lives in VMEM for the whole launch, so
-callers bound ``max_changed * chunk_bytes`` (the pipeline falls back to the
-unfused two-kernel path past its VMEM budget).
+Rows are lines read as int32 words (``words.py``).  Checksum lanes are
+defined over byte values at byte positions, so each word contributes its
+four bytes at the positions ``words.word_byte_pos`` names; int32
+arithmetic wraps exactly like the oracle's uint32.  A block
+with no dirty row skips the checksum math — its rows are never emitted.
+``count`` totals ALL dirty rows, so ``count > max_changed`` signals
+capacity overflow (the caller falls back to a full-grid dump).
 """
 from __future__ import annotations
 
+import functools
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .ref import CHECKSUM_LANES, chunk_checksums_ref
+from . import words
+from .delta_diff import block_dirty
+from .ref import _CS_ADD, _CS_MULT, _CS_XOR, CHECKSUM_LANES
 
-__all__ = ["delta_fused"]
+__all__ = ["delta_fused", "compact_rows"]
+
+# int32 views of the checksum constants (uint32 wraparound == int32 wrap)
+_MULT, _ADD, _XOR = np.array([_CS_MULT, _CS_ADD, _CS_XOR], np.uint32).view(np.int32)
 
 
-def _fused_kernel(old_ref, new_ref, data_ref, idx_ref, cnt_ref, sums_ref, *, cap: int):
-    i = pl.program_id(0)
+def _lane_sums(x, first_line, itemsize: int, rb: int):
+    """Per-row lane-partial checksums of an int32 word block ``(b, r, 128)``
+    whose first word line is row word line ``first_line``: four
+    ``(b, 1, 128)`` int32."""
+    shape = (1,) + x.shape[1:]
+    line = first_line + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    s = [jnp.zeros(x.shape, jnp.int32) for _ in range(CHECKSUM_LANES)]
+    for q in range(4):
+        byte = jax.lax.shift_right_logical(x, jnp.int32(8 * q)) & 0xFF
+        pos = words.word_byte_pos(line, lane, q, itemsize)
+        w = pos * _MULT + _ADD
+        s[0] = s[0] + byte
+        s[1] = s[1] + byte * (pos + 1)
+        s[2] = s[2] + byte * w
+        s[3] = s[3] + jnp.where(pos < rb, (byte + 1) * (w ^ _XOR), 0)
+    return [jnp.sum(v, axis=1, keepdims=True) for v in s]
 
-    @pl.when(i == 0)
+
+def _fused_kernel(old_ref, new_ref, dirty_ref, sums_ref, *, rb: int, split: bool, ch: int):
+    k = pl.program_id(1)
+    itemsize = jnp.dtype(new_ref.dtype).itemsize
+    b, r, _ = new_ref.shape
+    cur = block_dirty(old_ref, new_ref, ch)                   # (b, 1, 128)
+
+    @pl.when(k == 0)
     def _init():
-        # zero-filled unused slots / -1 idx padding: bit-identical layout to
-        # the jnp oracle, so parity tests compare whole buffers
-        data_ref[...] = jnp.zeros(data_ref.shape, data_ref.dtype)
-        idx_ref[...] = jnp.full(idx_ref.shape, -1, jnp.int32)
-        sums_ref[...] = jnp.zeros(sums_ref.shape, jnp.uint32)
-        cnt_ref[0, 0] = 0
+        dirty_ref[...] = cur
+        sums_ref[...] = jnp.zeros(sums_ref.shape, jnp.int32)
 
-    old = old_ref[...]
-    new = new_ref[...]
-    B = old.shape[0]
-    # one read of old+new: dirty reduction and the checksum lanes share it
-    dirty = jnp.max((old != new).astype(jnp.int32), axis=1)      # (B,)
-    sums = chunk_checksums_ref(new)                              # (B, LANES)
+    @pl.when(k > 0)
+    def _fold():
+        dirty_ref[...] = jnp.maximum(dirty_ref[...], cur)
 
-    def _row(j, cnt):
-        d = dirty[j]
+    def _sums():
+        def body(c, acc):
+            sl = pl.ds(pl.multiple_of(c * ch, ch), ch)
+            x = words.as_words(new_ref[:, sl, :])
+            first = (k * r + c * ch) * itemsize // 4
+            return tuple(a + v for a, v in zip(acc, _lane_sums(x, first, itemsize, rb)))
 
-        @pl.when((d > 0) & (cnt < cap))
-        def _emit():
-            data_ref[pl.ds(cnt, 1), :] = jax.lax.dynamic_slice_in_dim(new, j, 1, axis=0)
-            idx_ref[pl.ds(cnt, 1), :] = jnp.full((1, 1), i * B + j, jnp.int32)
-            sums_ref[pl.ds(cnt, 1), :] = jax.lax.dynamic_slice_in_dim(sums, j, 1, axis=0)
+        zero = jnp.zeros((b, 1, words.LANES), jnp.int32)
+        lanes = jax.lax.fori_loop(0, r // ch, body, (zero,) * CHECKSUM_LANES)
+        for t, v in enumerate(lanes):
+            sums_ref[:, t : t + 1, :] = sums_ref[:, t : t + 1, :] + v
 
-        return cnt + d                 # count every dirty row, past cap too
+    # a split row may turn dirty in a later sub-block, so its checksum
+    # parts are always summed; whole-row blocks skip clean blocks
+    if split:
+        _sums()
+    else:
+        pl.when(jnp.max(cur) > 0)(_sums)
 
-    cnt_ref[0, 0] = jax.lax.fori_loop(0, B, _row, cnt_ref[0, 0])
+
+def compact_rows(new: jax.Array, dirty: jax.Array, max_changed: int):
+    """Dirty rows of ``new`` front-packed in ascending order: (data, idx,
+    count), bit-identical to ``ref.delta_compact_ref`` but a gather of
+    ``max_changed`` rows instead of a scatter of all ``N``."""
+    count = jnp.sum(dirty.astype(jnp.int32))
+    idx = jnp.nonzero(dirty, size=max_changed, fill_value=-1)[0].astype(jnp.int32)
+    rows = jnp.take(new, jnp.maximum(idx, 0), axis=0)
+    data = jnp.where((idx >= 0)[:, None], rows, jnp.zeros((), new.dtype))
+    return data, idx, count
 
 
 def delta_fused(
@@ -73,7 +106,7 @@ def delta_fused(
     new: jax.Array,     # (N, C)
     *,
     max_changed: int,
-    chunk_block: int = 8,
+    chunk_block: Optional[int] = None,
     interpret: bool = False,
 ):
     """Fused diff+compact+checksum: (data, idx, count, sums).
@@ -84,35 +117,36 @@ def delta_fused(
     uint32 zeroed on unused slots.
     """
     assert old.shape == new.shape and old.dtype == new.dtype
-    N, C = old.shape
+    N = old.shape[0]
     cap = int(max_changed)
     assert cap >= 1
-    block = min(chunk_block, N)
-    if N % block:
-        # pad with identical zero rows: never dirty, never emitted
-        pad = ((0, block - N % block), (0, 0))
-        old = jnp.pad(old, pad)
-        new = jnp.pad(new, pad)
-    grid = (pl.cdiv(old.shape[0], block),)
-    data, idx, count, sums = pl.pallas_call(
-        lambda *refs: _fused_kernel(*refs, cap=cap),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block, C), lambda i: (i, 0)),
-            pl.BlockSpec((block, C), lambda i: (i, 0)),
-        ],
+    rb = old.shape[1] * jnp.dtype(old.dtype).itemsize
+    b, r, lines = words.block_plan(N, old.shape[1], old.dtype, chunk_block)
+    ol = words.pad_rows(words.to_lines(old, lines), b)
+    nl = words.pad_rows(words.to_lines(new, lines), b)
+    n_pad = ol.shape[0]
+    spec = pl.BlockSpec((b, r, words.LANES), lambda i, k: (i, k, 0))
+    dirty, sums = pl.pallas_call(
+        functools.partial(
+            _fused_kernel, rb=rb, split=lines > r, ch=words.chunk_lines(r, old.dtype)
+        ),
+        grid=(n_pad // b, lines // r),
+        in_specs=[spec, spec],
         out_specs=[
-            pl.BlockSpec((cap, C), lambda i: (0, 0)),
-            pl.BlockSpec((cap, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((cap, CHECKSUM_LANES), lambda i: (0, 0)),
+            pl.BlockSpec((b, 1, words.LANES), lambda i, k: (i, 0, 0)),
+            pl.BlockSpec((b, CHECKSUM_LANES, words.LANES), lambda i, k: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((cap, C), new.dtype),
-            jax.ShapeDtypeStruct((cap, 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((cap, CHECKSUM_LANES), jnp.uint32),
+            jax.ShapeDtypeStruct((n_pad, 1, words.LANES), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, CHECKSUM_LANES, words.LANES), jnp.int32),
         ],
         interpret=interpret,
-    )(old, new)
-    return data, idx[:, 0], count[0, 0], sums
+    )(ol, nl)
+    dirty = jnp.any(dirty[:N, 0] != 0, axis=-1)
+    row_sums = jnp.sum(
+        jax.lax.bitcast_convert_type(sums[:N], jnp.uint32), axis=-1, dtype=jnp.uint32
+    )
+    data, idx, count = compact_rows(new, dirty, cap)
+    valid = (idx >= 0)[:, None]
+    sums = jnp.where(valid, jnp.take(row_sums, jnp.maximum(idx, 0), axis=0), jnp.uint32(0))
+    return data, idx, count, sums

@@ -27,8 +27,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ref as _ref
+from . import tile_diff as _tile_diff
 from .delta_apply import delta_apply as _delta_apply_kernel
 from .delta_diff import delta_diff as _delta_diff_kernel
+from .delta_fused import compact_rows as _compact_rows
 from .delta_fused import delta_fused as _delta_fused_kernel
 from .page_copy import page_copy as _page_copy_kernel
 from .page_copy import page_copy_stacked as _page_copy_stacked_kernel
@@ -150,7 +152,7 @@ def delta_encode(old, new, max_changed: int):
         if _use_kernel()
         else _ref.delta_diff_ref(old, new)
     )
-    return _ref.delta_compact_ref(new, dirty, max_changed)
+    return _compact_rows(new, dirty, max_changed)
 
 
 @functools.partial(jax.jit, static_argnames=("max_changed",))
@@ -174,48 +176,17 @@ def fused_encode(old, new, max_changed: int):
 
 @functools.partial(jax.jit, static_argnames=("counts", "tile", "max_changed"))
 def shard_block_encode(old, new, counts, tile, max_changed: int):
-    """Block-native diff + compact for one shard part.
+    """Block-native diff + compact for one shard part (``tile_diff``).
 
     Same (data, idx, count) contract as ``delta_encode``, but over the
-    shard's NATIVE block layout instead of a
-    materialized tile grid: per-tile dirtiness is a compare + reduce (one
-    read of old and new, nothing written back), and only the ``max_changed``
-    dirty tiles' bytes are extracted — each as the row-major tile bitcast to
-    uint8, bit-identical to the matching ``_device_tile_grid`` row.  Device
+    shard's NATIVE block layout instead of a materialized tile grid: device
     work is O(state) reads + O(delta) writes, where the grid path pays two
     O(state) byte-transposes per dump (old + new) before it ever diffs.
     """
-    nd = len(counts)
-    inter: list = []
-    for c, t in zip(counts, tile):
-        inter.extend((c, t))
-    neq = (old != new).reshape(inter)
-    dirty = jnp.any(neq, axis=tuple(2 * i + 1 for i in range(nd))).reshape(-1)
-    count = jnp.sum(dirty.astype(jnp.int32))
-    # ascending order, -1 padding at the tail, first-capacity overflow drop:
-    # the exact delta_compact_ref slot contract
-    idx = jnp.nonzero(dirty, size=max_changed, fill_value=-1)[0].astype(jnp.int32)
-    # extract the selected tiles as a flat gather — work ∝ max_changed tiles,
-    # never an O(block) tile-grid transpose.  Element offsets of one tile
-    # (row-major over the tile, static) + the tile's base offset give each
-    # row's exact element indices in the native block.
-    block_shape = tuple(c * t for c, t in zip(counts, tile))
-    estrides = np.ones(nd, np.int64)
-    for i in range(nd - 2, -1, -1):
-        estrides[i] = estrides[i + 1] * block_shape[i + 1]
-    tcoords = np.indices(tile).reshape(nd, -1)
-    t_off = (tcoords * estrides[:, None]).sum(0)             # (tile_elems,)
-    ccoords = jnp.unravel_index(jnp.maximum(idx, 0), counts)
-    # int32 offsets: fine below 2**31 elements per shard block (8 GiB f32)
-    base = sum(
-        c.astype(jnp.int32) * np.int32(t * s)
-        for c, t, s in zip(ccoords, tile, estrides)
+    return _tile_diff.block_encode(
+        old, new, counts, tile, max_changed,
+        use_kernel=_use_kernel(), interpret=use_interpret(),
     )
-    flat_idx = base[:, None] + jnp.asarray(t_off, jnp.int32)[None, :]
-    rows = jnp.take(new.reshape(-1), flat_idx)               # (cap, tile_elems)
-    u8 = jax.lax.bitcast_convert_type(rows, jnp.uint8).reshape(max_changed, -1)
-    data = jnp.where((idx >= 0)[:, None], u8, jnp.uint8(0))
-    return data, idx, count
 
 
 @jax.jit
@@ -243,9 +214,10 @@ def chunk_checksums_host(chunks: np.ndarray) -> np.ndarray:
     against the device-computed lanes without a jax round-trip — one
     vectorized pass at host memory bandwidth.
     """
-    x = np.ascontiguousarray(chunks).astype(np.uint32)
+    x = np.ascontiguousarray(chunks)
     if x.ndim == 1:
         x = x[None, :]
+    x = x.view(np.uint8).reshape(x.shape[0], -1).astype(np.uint32)
     C = x.shape[-1]
     pos = np.arange(C, dtype=np.uint32)[None, :]
     w = pos * _CS_MULT + _CS_ADD

@@ -15,10 +15,14 @@ Layout:
                               valid page ids (the pool keeps page 0 reserved)
   seq_lens   (B,)             int32
 
-Grid (B, KVH, max_pages); the page axis iterates fastest and carries a
-flash-style running (m, l, acc) in VMEM scratch.  MXU work per step is the
-(G × D) · (D × page_size) score matmul; page_size and D are chosen
-128-multiples so K/V tiles are MXU/VREG aligned.
+Grid (B, max_pages); the page axis iterates fastest and carries a
+flash-style running (m, l, acc) in VMEM scratch.  One K/V block is a whole
+page over every kv head, ``(1, page_size, KVH, D)``: its last two dims are
+the array's own, which the TPU block rule accepts for any head count (a
+one-head block is refused).  Scores and the weighted sum run per kv head on
+the vector unit, ``G`` query rows at a time; decode attention reads each
+page once and is bound by that read.  Grid steps past a session's last
+page re-map to that page, so its K/V are not fetched again.
 """
 from __future__ import annotations
 
@@ -41,21 +45,22 @@ def _paged_attention_kernel(
     seq_lens_ref,      # (B,)
     page_table_ref,    # (B, max_pages)
     # blocks
-    q_ref,             # (1, 1, G, D)
-    k_ref,             # (1, page_size, 1, D)
-    v_ref,             # (1, page_size, 1, D)
-    o_ref,             # (1, 1, G, D)
+    q_ref,             # (1, G, KVH, D)
+    k_ref,             # (1, page_size, KVH, D)
+    v_ref,             # (1, page_size, KVH, D)
+    o_ref,             # (1, G, KVH, D)
     # scratch
-    m_scratch,         # (G, _LANES) f32
-    l_scratch,         # (G, _LANES) f32
-    acc_scratch,       # (G, D) f32
+    m_scratch,         # (G, KVH, _LANES) f32
+    l_scratch,         # (G, KVH, _LANES) f32
+    acc_scratch,       # (G, KVH, D) f32
     *,
     page_size: int,
     num_page_steps: int,
     scale: float,
 ):
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
+    G = q_ref.shape[1]
 
     @pl.when(i == 0)
     def _init():
@@ -68,34 +73,29 @@ def _paged_attention_kernel(
 
     @pl.when(page_start < seq_len)  # skip fully-masked pages
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale                 # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                   # (page_size, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )                                                            # (G, page_size)
-        pos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < seq_len, s, _NEG_INF)
-
-        m_prev = m_scratch[:, :1]                                    # (G, 1)
-        l_prev = l_scratch[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)                    # (G, 1)
-        m_next = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)                                      # (G, page_size)
-        l_next = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc_scratch[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scratch[...] = jnp.broadcast_to(m_next, m_scratch.shape)
-        l_scratch[...] = jnp.broadcast_to(l_next, l_scratch.shape)
-        acc_scratch[...] = acc
+        k = k_ref[0].astype(jnp.float32)                             # (psz, KVH, D)
+        v = v_ref[0].astype(jnp.float32)
+        pos = page_start + jax.lax.broadcasted_iota(jnp.int32, (page_size, 1, 1), 0)
+        for g in range(G):
+            q = q_ref[0, g].astype(jnp.float32) * scale              # (KVH, D)
+            s = jnp.sum(k * q[None], axis=2, keepdims=True)          # (psz, KVH, 1)
+            s = jnp.where(pos < seq_len, s, _NEG_INF)
+            m_prev = m_scratch[g][:, :1]                             # (KVH, 1)
+            l_prev = l_scratch[g][:, :1]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=0))         # (KVH, 1)
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(s - m_next[None])                            # (psz, KVH, 1)
+            l_next = alpha * l_prev + jnp.sum(p, axis=0)
+            acc_scratch[g] = acc_scratch[g] * alpha + jnp.sum(p * v, axis=0)
+            m_scratch[g] = jnp.broadcast_to(m_next, m_scratch.shape[1:])
+            l_scratch[g] = jnp.broadcast_to(l_next, l_scratch.shape[1:])
 
     @pl.when(i == num_page_steps - 1)
     def _finalize():
-        l = l_scratch[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)                              # seq_len == 0 guard
-        o_ref[0, 0] = (acc_scratch[...] / l).astype(o_ref.dtype)
+        for g in range(G):
+            l = l_scratch[g][:, :1]
+            l = jnp.where(l == 0.0, 1.0, l)                          # seq_len == 0 guard
+            o_ref[0, g] = (acc_scratch[g] / l).astype(o_ref.dtype)
 
 
 def paged_attention(
@@ -123,25 +123,34 @@ def paged_attention(
         num_page_steps=max_pages,
         scale=float(scale),
     )
-    grid = (B, KVH, max_pages)
-    q_spec = pl.BlockSpec((1, 1, G, D), lambda b, h, i, sl, pt: (b, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, page_size, 1, D), lambda b, h, i, sl, pt: (pt[b, i], 0, h, 0))
-    o_spec = pl.BlockSpec((1, 1, G, D), lambda b, h, i, sl, pt: (b, h, 0, 0))
 
+    def kv_map(b, i, sl, pt):
+        last = jnp.maximum((sl[b] - 1) // page_size, 0)
+        return (pt[b, jnp.minimum(i, last)], 0, 0, 0)
+
+    q_spec = pl.BlockSpec((1, G, KVH, D), lambda b, i, sl, pt: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, page_size, KVH, D), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(B, max_pages),
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=o_spec,
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((G, _LANES), jnp.float32),
-            pltpu.VMEM((G, _LANES), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((G, KVH, _LANES), jnp.float32),
+            pltpu.VMEM((G, KVH, _LANES), jnp.float32),
+            pltpu.VMEM((G, KVH, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, KVH, D), q.dtype),
         interpret=interpret,
-    )(seq_lens.astype(jnp.int32), page_table.astype(jnp.int32), q, k_pages, v_pages)
+    )(
+        seq_lens.astype(jnp.int32),
+        page_table.astype(jnp.int32),
+        jnp.swapaxes(q, 1, 2),
+        k_pages,
+        v_pages,
+    )
+    return jnp.swapaxes(out, 1, 2)

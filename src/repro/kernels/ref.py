@@ -130,12 +130,15 @@ def delta_compact_ref(
 def chunk_checksums_ref(chunks: jax.Array) -> jax.Array:
     """Per-row 4-lane uint32 checksums of an (N, C) chunk grid.
 
-    Pure elementwise-multiply + row-sum in uint32 (wraparound) — the exact
-    formulas the fused Pallas kernel evaluates per block, and mirrored in
-    numpy by ``ops.chunk_checksums_host``.  Lane 0 is order-insensitive;
-    lanes 1-3 weight by byte position so transpositions and shifts change
-    the value.  Returns (N, CHECKSUM_LANES) uint32.
+    Pure elementwise-multiply + row-sum in uint32 (wraparound) over the
+    row's bytes (a wider dtype counts as its little-endian bytes) — the
+    exact formulas the fused Pallas kernel evaluates per block, and
+    mirrored in numpy by ``ops.chunk_checksums_host``.  Lane 0 is
+    order-insensitive; lanes 1-3 weight by byte position so transpositions
+    and shifts change the value.  Returns (N, CHECKSUM_LANES) uint32.
     """
+    if chunks.dtype != jnp.uint8:
+        chunks = jax.lax.bitcast_convert_type(chunks, jnp.uint8).reshape(chunks.shape[0], -1)
     x = chunks.astype(jnp.uint32)
     C = x.shape[-1]
     pos = jax.lax.broadcasted_iota(jnp.uint32, (1, C), 1)

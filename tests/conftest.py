@@ -22,13 +22,6 @@ os.environ.setdefault("REPRO_INTERPRET", "1")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))
 
-try:  # real hypothesis when available (see requirements-dev.txt)
-    import hypothesis  # noqa: F401
-except ImportError:  # hermetic image: deterministic in-repo fallback
-    from _hypothesis_fallback import install
-
-    install()
-
 
 def pytest_configure(config):
     config.addinivalue_line(

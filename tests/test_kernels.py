@@ -196,3 +196,79 @@ def test_fused_encode_property(n_chunks, c_scale, dirty_frac):
             np.asarray(fsums)[valid],
             ops.chunk_checksums_host(np.asarray(fdata)[valid]),
         )
+
+
+# ------------------------------------------------- row layout edge cases
+@pytest.mark.parametrize("dtype", [jnp.uint8, jnp.bfloat16, jnp.float32])
+def test_delta_kernels_split_rows(monkeypatch, dtype):
+    """Rows larger than one block split over the inner grid axis: dirtiness,
+    checksum lanes and the scatter still match the oracles bit for bit."""
+    from repro.kernels import words
+    from repro.kernels.delta_apply import delta_apply
+    from repro.kernels.delta_diff import delta_diff
+    from repro.kernels.delta_fused import delta_fused
+
+    monkeypatch.setattr(words, "BLOCK_BYTES", 16 * 1024)
+    N, C = 5, 40 * 1024 // jnp.dtype(dtype).itemsize
+    old = (_rand((N, C), jnp.float32) * 50).astype(dtype)
+    new = old.at[1, -1].add(jnp.asarray(1, dtype))    # last sub-block only
+    new = new.at[3, 7].add(jnp.asarray(1, dtype))      # first sub-block only
+    assert words.block_plan(N, C, dtype)[1] < words.block_plan(N, C, dtype)[2]
+
+    dirty = delta_diff(old, new, interpret=True)
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(ref.delta_diff_ref(old, new)))
+    got = delta_fused(old, new, max_changed=3, interpret=True)
+    want = ref.fused_encode_ref(old, new, 3)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    rebuilt = delta_apply(old, got[0], got[1], interpret=True)
+    np.testing.assert_array_equal(np.asarray(rebuilt), np.asarray(new))
+
+
+@pytest.mark.parametrize(
+    "idx", [[-1, 3, -1, -1], [-1, -1, 0, 2], [4, -1, 1, -1], [-1, -1, -1, -1]]
+)
+def test_delta_apply_padding_anywhere(idx):
+    """-1 rows never change the base, wherever they sit in the index."""
+    from repro.kernels.delta_apply import delta_apply
+
+    base = _rand((6, 256), jnp.float32)
+    data = _rand((4, 256), jnp.float32)
+    idx = jnp.asarray(idx, jnp.int32)
+    got = delta_apply(base, data, idx, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(ref.delta_apply_ref(base, data, idx))
+    )
+
+
+@pytest.mark.parametrize(
+    "dtype,shape,counts",
+    [
+        (jnp.float32, (64, 256), (2, 2)),
+        (jnp.bfloat16, (64, 512), (2, 2)),
+        (jnp.uint8, (128, 256), (2, 1)),
+        (jnp.float32, (2, 16, 256), (2, 2, 1)),
+    ],
+)
+def test_tile_dirty_matches_jnp(dtype, shape, counts):
+    """Block-native per-tile diff kernel == the jnp reduce it replaces, and
+    block_encode agrees with its jnp path row for row."""
+    from repro.kernels import tile_diff
+
+    tile = tuple(s // c for s, c in zip(shape, counts))
+    assert tile_diff.kernel_fits(tile, dtype)
+    rng = np.random.default_rng(9)
+    old = jnp.asarray(rng.integers(0, 100, shape), dtype)
+    new = old.at[(0,) * len(shape)].add(jnp.asarray(1, dtype))
+    new = new.at[tuple(s - 1 for s in shape)].add(jnp.asarray(3, dtype))
+    got = tile_diff.tile_dirty(old, new, counts, tile, interpret=True)
+    inter = [d for c, t in zip(counts, tile) for d in (c, t)]
+    want = jnp.any(
+        (old != new).reshape(inter), axis=tuple(2 * i + 1 for i in range(len(shape)))
+    ).reshape(-1)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    cap = int(np.prod(counts))
+    k = tile_diff.block_encode(old, new, counts, tile, cap, use_kernel=True, interpret=True)
+    j = tile_diff.block_encode(old, new, counts, tile, cap, use_kernel=False)
+    for a, b in zip(k, j):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
