@@ -1,0 +1,2 @@
+"""decode_step_ms.fanout: host time of one Engine.step, over the steps of the window."""
+from bench.readers import decode_step_ms as read  # noqa: F401

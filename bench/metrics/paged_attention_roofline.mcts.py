@@ -1,0 +1,6 @@
+"""paged_attention_roofline.mcts: the paged attention kernel's share of its roofline."""
+from bench.readers import attention_calls, roofline
+
+
+def read(run):
+    return roofline(run, "paged_attention", attention_calls(run))
