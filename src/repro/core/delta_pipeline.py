@@ -53,7 +53,7 @@ from typing import (
 
 import numpy as np
 
-from . import faults
+from . import faults, spans
 from .chunk_store import ChunkStore, chunk_digest
 from .deltafs import TensorMeta, digest_encode_array  # noqa: F401 (re-export)
 from .faults import FaultError
@@ -266,6 +266,7 @@ class EncodeResult:
     streamed: bool = False
     windows: int = 0
     window_bytes: int = 0            # the (possibly adaptive) budget used
+    # stage times on either path (the dump.* spans' boundaries)
     encode_ms: float = 0.0
     drain_ms: float = 0.0
     commit_ms: float = 0.0
@@ -321,9 +322,6 @@ class _KeyTask:
     encode: Callable[[], Any]
     drain: Callable[[Any], Any]
     commit: Callable[[Any], Tuple[TensorMeta, int, str]]
-
-    def run_sync(self) -> Tuple[TensorMeta, int, str]:
-        return self.commit(self.drain(self.encode()))
 
     def as_window_item(self) -> WindowItem:
         return WindowItem(
@@ -1128,7 +1126,15 @@ class DeltaDumpPipeline:
                 raise StreamCancelled(
                     f"dump cancelled after {len(res.entries)} tensors (sync path)"
                 )
-            out = task.run_sync()
+            with spans.timed("dump.encode") as enc_t:
+                enc = task.encode()
+            with spans.timed("dump.drain") as drain_t:
+                raw = task.drain(enc)
+            with spans.timed("dump.commit") as commit_t:
+                out = task.commit(raw)
+            res.encode_ms += enc_t.ms
+            res.drain_ms += drain_t.ms
+            res.commit_ms += commit_t.ms
             if isinstance(out, _ShardRows):
                 shard_out.setdefault(out.plan_key, []).append(out)
             else:
@@ -1356,13 +1362,6 @@ class DeltaDumpPipeline:
                     for i in dirty:
                         grid_np[i] = np.frombuffer(store.get(meta.chunk_ids[i]), np.uint8)
                 else:
-                    base_grid = jnp.asarray(base.grid)
-                    if base.n_chunks < N:
-                        base_grid = jnp.zeros((N, base.chunk_bytes), jnp.uint8).at[
-                            : base.n_chunks
-                        ].set(base_grid)
-                    elif base.n_chunks > N:
-                        base_grid = base_grid[:N]
                     if dirty:
                         # pow2-pad the scatter rows (idx -1 = no-op in the
                         # kernel) so delta_apply compiles per geometry, not
@@ -1373,16 +1372,28 @@ class DeltaDumpPipeline:
                         for j, i in enumerate(dirty):
                             rows[j] = np.frombuffer(store.get(meta.chunk_ids[i]), np.uint8)
                             idx[j] = i
-                        grid_np = np.asarray(
-                            kops.delta_apply(base_grid, jnp.asarray(rows), jnp.asarray(idx))
-                        )
-                    else:
+                    # the base grid up (when held on the host), the scatter,
+                    # and the whole rebuilt grid back to the host
+                    with spans.span("deltacr.apply", rows=len(dirty)):
+                        base_grid = jnp.asarray(base.grid)
+                        if base.n_chunks < N:
+                            base_grid = jnp.zeros((N, base.chunk_bytes), jnp.uint8).at[
+                                : base.n_chunks
+                            ].set(base_grid)
+                        elif base.n_chunks > N:
+                            base_grid = base_grid[:N]
+                        if dirty:
+                            base_grid = kops.delta_apply(
+                                base_grid, jnp.asarray(rows), jnp.asarray(idx)
+                            )
                         grid_np = np.asarray(base_grid)
                 payload[name] = self._grid_to_array(grid_np, meta)
             else:
-                payload[name] = store.get_array(
-                    meta.chunk_ids, meta.shape, np.dtype(meta.dtype)
-                )
+                # no base generation: every chunk from the store
+                with spans.span("deltacr.read", rows=len(meta.chunk_ids)):
+                    payload[name] = store.get_array(
+                        meta.chunk_ids, meta.shape, np.dtype(meta.dtype)
+                    )
             # register the rebuilt tensor as a future base
             row_bytes = (
                 base.chunk_bytes
@@ -1401,7 +1412,8 @@ class DeltaDumpPipeline:
                         grid_fn=lambda g=grid_np: g,
                     )
                 else:
-                    view = self._view_from_array(payload[name], meta, row_bytes)
+                    with spans.span("deltacr.keep_base"):
+                        view = self._view_from_array(payload[name], meta, row_bytes)
                 if view is not None:
                     new_views[name] = view
         return payload, new_views

@@ -35,6 +35,7 @@ the device-side one.
 """
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
@@ -52,7 +53,7 @@ from typing import Any, Callable, Dict, List, Optional, Protocol, Set, Tuple, ru
 
 import numpy as np
 
-from . import faults
+from . import faults, spans
 from .chunk_store import ChunkStore, chunk_digest
 from .delta_pipeline import (
     ChunkedView,
@@ -101,6 +102,7 @@ class _EitherEvent:
 
 
 _LIVE_WORKERS: "weakref.WeakSet[_SupervisedWorker]" = weakref.WeakSet()
+_CR_IDS = itertools.count()          # ``cr`` span arg: which DeltaCR a ckpt id belongs to
 _WORKER_ATEXIT_ARMED = False
 
 
@@ -413,9 +415,10 @@ class DumpImage:
     streamed: bool = False
     stream_windows: int = 0
     stream_window_bytes: int = 0     # the (possibly EWMA-adapted) budget used
+    # pipeline stage times, streamed or not (the dump.* spans' boundaries)
     encode_ms: float = 0.0   # diff dispatch / host compare stage
-    drain_ms: float = 0.0    # device→host fetch + copy + hash stage (pool)
-    commit_ms: float = 0.0   # store folds + metadata stage (caller)
+    drain_ms: float = 0.0    # device→host fetch + copy + hash stage
+    commit_ms: float = 0.0   # store folds + metadata stage
     shard_parts: int = 0     # per-shard tasks run (0 = no sharded tensors)
 
 
@@ -572,6 +575,7 @@ class DeltaCR:
         # convention anywhere in the reclaim paths.
         self.images = ImageStore(self.store, evict_hook=self._evict_generation)
         self.stats = DeltaCRStats()
+        self.span_id = next(_CR_IDS)     # ``cr`` of this engine's spans
         # Verified-read repair: a corrupt stored chunk can be re-derived from
         # any anchored generation grid row that still maps to it.
         self.store.attach_repair_source(self._repair_from_generations)
@@ -651,14 +655,27 @@ class DeltaCR:
         Transactional: a template-fork failure raises here with nothing
         registered — no ticket, no template, no dump queued.
         """
+        with spans.span("deltacr.checkpoint", cr=self.span_id, ckpt=ckpt_id, dump=dump):
+            self._checkpoint(state, ckpt_id, parent_ckpt, dump, priority)
+
+    def _checkpoint(
+        self,
+        state: ForkableState,
+        ckpt_id: int,
+        parent_ckpt: Optional[int],
+        dump: bool,
+        priority: str,
+    ) -> None:
         faults.fire("template.fork")
-        template = state.fork()
+        with spans.span("deltacr.template_fork"):
+            template = state.fork()
         with self._lock:
             if dump:
                 # The dump holds its *own* fork: LRU eviction may release the
                 # pool template before the background dump runs, and a dump
                 # source must survive until serialization completes.
-                dump_src = template.fork()
+                with spans.span("deltacr.dump_fork"):
+                    dump_src = template.fork()
                 # The dirty-key hint is only valid relative to the checkpoint
                 # it was reset at.  A *branch* dump (parent differs from the
                 # session's tracking base, e.g. re-checkpointing from an
@@ -683,10 +700,11 @@ class DeltaCR:
                     if parent_fut is not None and parent_ckpt is not None
                     else None
                 )
-                fut = self._dump_worker.submit(
-                    self._do_dump, ckpt_id, ticket, dump_src, parent_fut,
-                    parent_ref, priority, cancel,
-                )
+                with spans.span("deltacr.submit"):
+                    fut = self._dump_worker.submit(
+                        self._do_dump, ckpt_id, ticket, dump_src, parent_fut,
+                        parent_ref, priority, cancel, time.perf_counter(),
+                    )
                 fut.add_done_callback(
                     lambda _f, c=ckpt_id: self._cancels.pop(c, None)
                 )
@@ -714,15 +732,22 @@ class DeltaCR:
         dump_src: ForkableState,
         parent_fut: Optional[Future],
         parent_ref,
-        priority: str = "bg",
-        cancel: Optional[threading.Event] = None,
+        priority: str,
+        cancel: threading.Event,
+        submitted: float,
     ) -> DumpImage:
-        try:
-            return self._dump_image(ckpt_id, ticket, dump_src, parent_fut, priority, cancel)
-        finally:
-            # lineage ref off: if the parent checkpoint was dropped while
-            # this dump ran, its chunks are returned here, not before
-            self.images.release(parent_ref)
+        # queued_ms: how long the dump waited in the FIFO behind others
+        queued_ms = (time.perf_counter() - submitted) * 1e3
+        with spans.span("dump", cr=self.span_id, ckpt=ckpt_id, queued_ms=queued_ms) as sp:
+            try:
+                image = self._dump_image(ckpt_id, ticket, dump_src, parent_fut, priority, cancel)
+            finally:
+                # lineage ref off: if the parent checkpoint was dropped while
+                # this dump ran, its chunks are returned here, not before
+                self.images.release(parent_ref)
+            if sp is not None:
+                sp.set_metadata(mode=image.mode, bytes=image.dump_bytes)
+            return image
 
     def _dump_image(
         self,
@@ -743,10 +768,11 @@ class DeltaCR:
             raise StreamCancelled(f"checkpoint {ckpt_id}: dump cancelled while queued")
         parent: Optional[DumpImage] = None
         if parent_fut is not None:
-            try:
-                parent = parent_fut.result(timeout=60.0)  # FIFO: already done
-            except Exception:
-                parent = None  # parent dump failed: fall back to a full image
+            with spans.span("dump.parent_wait"):
+                try:
+                    parent = parent_fut.result(timeout=60.0)  # FIFO: already done
+                except Exception:
+                    parent = None  # parent dump failed: fall back to a full image
         t0 = time.perf_counter()
         bytes_before = self.store.stats.bytes_written
         try:
@@ -1163,6 +1189,13 @@ class DeltaCR:
         materialized base generation when available — then re-inject the
         rebuilt state as a template so future restores take the fast path.
         """
+        with spans.span("deltacr.restore", cr=self.span_id, ckpt=ckpt_id) as sp:
+            new_state, path = self._restore(ckpt_id)
+            if sp is not None:
+                sp.set_metadata(path=path)
+        return new_state, path
+
+    def _restore(self, ckpt_id: int) -> Tuple[ForkableState, str]:
         with self._lock:
             template = self._templates.get(ckpt_id)
             if template is not None:
@@ -1180,7 +1213,8 @@ class DeltaCR:
             fut = self._images.get(ckpt_id)
         if fut is None:
             raise KeyError(f"checkpoint {ckpt_id}: no template and no dump image")
-        image = fut.result()  # may wait for the background dump to land
+        with spans.span("deltacr.image_wait"):
+            image = fut.result()  # may wait for the background dump to land
         if self.restore_fn is None:
             raise RuntimeError("slow-path restore requires restore_fn")
         # Decode under dependent references: a concurrent drop of this
@@ -1190,21 +1224,29 @@ class DeltaCR:
         if image_ref is None:
             raise KeyError(f"checkpoint {ckpt_id}: image was dropped")
         parent_ref = self.images.acquire_image(image.parent_id)
+        nbytes = 0
         try:
-            if self.pipeline is not None:
-                parent_image = self.images.get(image.parent_id)
-                payload = self.pipeline.decode(image, parent_image)
-            else:
-                payload = {
-                    name: self.store.get_array(meta.chunk_ids, meta.shape, np.dtype(meta.dtype))
-                    for name, meta in image.entries.items()
-                }
+            with spans.span("deltacr.decode", rows=image.dirtied_chunks) as sp:
+                if self.pipeline is not None:
+                    parent_image = self.images.get(image.parent_id)
+                    payload = self.pipeline.decode(image, parent_image)
+                else:
+                    payload = {
+                        name: self.store.get_array(meta.chunk_ids, meta.shape, np.dtype(meta.dtype))
+                        for name, meta in image.entries.items()
+                    }
+                if sp is not None:
+                    nbytes = sum(int(v.nbytes) for v in payload.values())
+                    sp.set_metadata(bytes=nbytes)
         finally:
             self.images.release(parent_ref)
             self.images.release(image_ref)
-        rebuilt = self.restore_fn(payload)
+        with spans.span("deltacr.upload", bytes=nbytes) as sp:
+            rebuilt = self.restore_fn(payload)
+            if sp is not None:
+                sp.set_metadata(pages=int(getattr(rebuilt, "n_pages", 0)))
         mark_unknown(rebuilt)
-        with self._lock:
+        with spans.span("deltacr.admit"), self._lock:
             # Re-inject as template (paper: restored process is frozen and
             # returned to the pool).
             self._admit_template(ckpt_id, rebuilt.fork())

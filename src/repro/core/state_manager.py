@@ -38,6 +38,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from . import spans
 from .delta_pipeline import mark_clean, mark_unknown
 from .deltacr import DeltaCR, ForkableState
 from .deltafs import DeltaFS, LayerConfig
@@ -239,6 +240,13 @@ class StateManager:
         O(metadata)).  The durable dump runs asynchronously, masked by the
         inference window.
         """
+        with spans.span("statemgr.checkpoint") as sp:
+            ckpt_id = self._checkpoint(lightweight, actions, dump)
+            if sp is not None:
+                sp.set_metadata(ckpt=ckpt_id)
+        return ckpt_id
+
+    def _checkpoint(self, lightweight: bool, actions: Tuple[Any, ...], dump: bool) -> int:
         with self._lock:
             if self.require_quiesce and not self.sandbox.quiesced():
                 raise CheckpointError("sandbox not quiesced: in-flight dispatch")
@@ -265,7 +273,8 @@ class StateManager:
                 return ckpt_id
 
             # 1. DeltaFS: synchronous freeze + fresh upper (the ioctl).
-            config = self.sandbox.fs.checkpoint()
+            with spans.span("statemgr.fs_freeze"):
+                config = self.sandbox.fs.checkpoint()
             try:
                 if self._fail_dump_for_test and self._fail_dump_for_test(ckpt_id):
                     raise CheckpointError("injected dump failure")
@@ -308,6 +317,13 @@ class StateManager:
         session state → resume.  The new session never observes mismatched
         files.
         """
+        with spans.span("statemgr.restore", ckpt=ckpt_id) as sp:
+            mode = self._restore(ckpt_id)
+            if sp is not None:
+                sp.set_metadata(path=mode)
+        return mode
+
+    def _restore(self, ckpt_id: int) -> str:
         with self._lock:
             node = self.nodes.get(ckpt_id)
             if node is None or node.reclaimed:
@@ -325,7 +341,8 @@ class StateManager:
 
             # 2. DeltaFS switch to the target configuration.
             assert full_node.layer_config is not None
-            self.sandbox.fs.switch(full_node.layer_config)
+            with spans.span("statemgr.fs_switch"):
+                self.sandbox.fs.switch(full_node.layer_config)
 
             # 3. DeltaCR fast/slow path.
             new_state, path = self.deltacr.restore(full)
@@ -339,7 +356,8 @@ class StateManager:
             # 4. LW replay: re-apply recorded read-only actions on top.
             mode = path
             if full != ckpt_id:
-                self.replay_lw_chain(self.sandbox, full, ckpt_id)
+                with spans.span("statemgr.replay"):
+                    self.replay_lw_chain(self.sandbox, full, ckpt_id)
                 mode = f"{path}+replay"
 
             self._current = ckpt_id

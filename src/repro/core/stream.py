@@ -40,7 +40,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
-from . import faults
+from . import faults, spans
 
 __all__ = [
     "ChunkStreamEngine",
@@ -121,18 +121,11 @@ class StreamStats:
         """sum-of-stages over wall: 1.0 = no overlap, >1 = real overlap."""
         return self.stage_sum_ms / self.wall_ms if self.wall_ms > 0 else 1.0
 
-    @property
-    def overlap_ms(self) -> float:
-        """Wall time hidden by the ping-pong: stage work that ran while
-        another stage held the clock.  0 for a fully serial dump."""
-        return max(0.0, self.stage_sum_ms - self.wall_ms)
-
 
 @dataclass
 class GateStats:
     acquires: int = 0
     demotions: int = 0               # windows that waited on runnable sessions
-    demote_wait_ms: float = 0.0
 
 
 class DumpGate:
@@ -189,16 +182,17 @@ class DumpGate:
             demoted = False
             max_s = self.demote_max_ms / 1e3
             with self._cv:
-                while self._runnable > 0:
-                    waited = time.monotonic() - t0
-                    if waited >= max_s:
-                        break
-                    demoted = True
-                    self._cv.wait(min(self.demote_poll_ms / 1e3, max_s - waited))
+                if self._runnable > 0:
+                    with spans.span("dump.gate_wait"):
+                        while self._runnable > 0:
+                            waited = time.monotonic() - t0
+                            if waited >= max_s:
+                                break
+                            demoted = True
+                            self._cv.wait(min(self.demote_poll_ms / 1e3, max_s - waited))
             if demoted:
                 with self._stats_lock:
                     self.stats.demotions += 1
-                    self.stats.demote_wait_ms += (time.monotonic() - t0) * 1e3
         self._slots.acquire()
         with self._stats_lock:
             self.stats.acquires += 1
@@ -380,9 +374,9 @@ class ChunkStreamEngine:
                 if gate_stats is not None:
                     stats.demoted_windows += gate_stats.demotions - demote_before
                 try:
-                    t0 = time.perf_counter()
-                    encoded = [(it, it.encode()) for it in window]
-                    stats.encode_ms += (time.perf_counter() - t0) * 1e3
+                    with spans.timed("dump.encode") as enc_t:
+                        encoded = [(it, it.encode()) for it in window]
+                    stats.encode_ms += enc_t.ms
                 except BaseException as e:          # encode failed: slot back
                     gate.release()
                     error = e
@@ -420,12 +414,12 @@ class ChunkStreamEngine:
         try:
             drained, drain_ms = fut.result()
             stats.drain_ms += drain_ms
-            t0 = time.perf_counter()
-            for item, raw in zip(window, drained):
-                if cancel is not None and cancel.is_set():
-                    return False
-                results[item.key] = item.commit(raw)
-            stats.commit_ms += (time.perf_counter() - t0) * 1e3
+            with spans.timed("dump.commit") as commit_t:
+                for item, raw in zip(window, drained):
+                    if cancel is not None and cancel.is_set():
+                        return False
+                    results[item.key] = item.commit(raw)
+            stats.commit_ms += commit_t.ms
             return len(drained) == len(window)      # short drain = cancelled
         finally:
             gate.release()
@@ -459,12 +453,12 @@ class ChunkStreamEngine:
         # get exercised
         faults.fire("stream.drain")
         out = []
-        t0 = time.perf_counter()
-        for item, enc in encoded:
-            if cancel is not None and cancel.is_set():
-                break                                # partial window: no commit
-            out.append(item.drain(enc))
-        return out, (time.perf_counter() - t0) * 1e3
+        with spans.timed("dump.drain") as drain_t:
+            for item, enc in encoded:
+                if cancel is not None and cancel.is_set():
+                    break                            # partial window: no commit
+                out.append(item.drain(enc))
+        return out, drain_t.ms
 
     def shutdown(self) -> None:
         self._shut = True

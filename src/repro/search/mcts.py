@@ -91,7 +91,6 @@ class MCTSStats:
     time_restore_s: float = 0.0
     time_checkpoint_s: float = 0.0
     time_action_s: float = 0.0
-    time_eval_s: float = 0.0
     best_value: float = 0.0
     nodes: int = 0
     wall_s: float = 0.0
@@ -246,9 +245,7 @@ class MCTS:
                     st.slow_restores += 1
             node = sm.node(target)
             if node.terminal:
-                t0 = time.perf_counter()
                 value = task.evaluate(sm.sandbox)
-                st.time_eval_s += time.perf_counter() - t0
                 self._backprop(target, value)
                 continue
             # 3. expansion: apply one untried action, checkpoint the child
@@ -276,12 +273,10 @@ class MCTS:
             self._register(child, self.depth[target] + 1, cfg.seed + it + 1)
 
             # 4. evaluation under value-time isolation
-            t0 = time.perf_counter()
             if cfg.value_isolation:
                 value = sm.isolated_eval(lambda sb: task.evaluate(sb))
             else:
                 value = task.evaluate(sm.sandbox)
-            st.time_eval_s += time.perf_counter() - t0
             st.best_value = max(st.best_value, value)
 
             # 5. backprop
@@ -396,10 +391,7 @@ class MCTS:
             sandbox.sched_sid = sched_sid
         try:
             if action is None:
-                t0 = time.perf_counter()
                 value = task.evaluate(sandbox)
-                with self._stats_lock:
-                    st.time_eval_s += time.perf_counter() - t0
                 return None, value, [], False
 
             t0 = time.perf_counter()
@@ -423,7 +415,6 @@ class MCTS:
             # which registers the child and only then evaluates under
             # isolation.  The evaluation's pollution then dies with the fork.
             try:
-                t0 = time.perf_counter()
                 terminal = (
                     task.is_terminal(sandbox)
                     or self.depth[target] + 1 >= cfg.max_depth
@@ -432,7 +423,6 @@ class MCTS:
                 if not terminal:
                     actions = list(task.propose_actions(sandbox, seed))[: cfg.expand_width]
                 value = task.evaluate(sandbox)
-                t_eval = time.perf_counter() - t0
             except BaseException:
                 # the adopted child would otherwise be an orphan the driver
                 # never registers but GC protects forever — reclaim it
@@ -446,7 +436,6 @@ class MCTS:
             with self._stats_lock:
                 st.time_action_s += t_action
                 st.time_checkpoint_s += t_ckpt
-                st.time_eval_s += t_eval
                 st.checkpoints += 1
                 if lw:
                     st.lw_checkpoints += 1

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.core import spans
 from repro.models.model import Model
 from .kvcache import PagePool, PagedSession
 
@@ -51,7 +52,6 @@ class Engine:
         self.cfg = model.cfg
         self._decode_jit: Dict[int, Any] = {}
         self._prefill_jit: Dict[int, Any] = {}
-        self.decode_steps = 0
 
     # ------------------------------------------------------------ sessions
     def new_session(
@@ -100,31 +100,39 @@ class Engine:
         # the whole step holds the pool lock: the async-warm worker commits
         # materializations into the same pool arrays this step functionally
         # updates, and an interleaved commit would be silently overwritten
-        with self.pool.lock:
-            plans: List[Any] = []
-            try:
-                for s in sessions:
-                    plans.append(s.plan_writable(extra_tokens=1))
-            except BaseException:
-                self.pool.discard_plans(plans)
-                raise
-            self.pool.materialize(plans)
-            # 2. stacked decode
-            last = [s.tokens[-1] for s in sessions]
-            cache = self._build_cache(sessions)
-            tokens = jnp.asarray(last, jnp.int32)
-            decode = self._get_decode(len(sessions))
-            logits, new_cache = decode(self.params, tokens, cache)
-            self._absorb_cache(sessions, new_cache, advance=True)
-        # 3. sampling with checkpointable rng
-        out = []
-        logits_np = np.asarray(logits, np.float32)
-        for i, s in enumerate(sessions):
-            tok = self._sample(s, logits_np[i])
-            s.tokens.append(tok)
-            s.extras["last_logits"] = logits_np[i]
-            out.append(tok)
-        self.decode_steps += 1
+        with spans.span("engine.step", batch=len(sessions)):
+            with self.pool.lock:
+                with spans.span("engine.plan") as sp:
+                    plans: List[Any] = []
+                    try:
+                        for s in sessions:
+                            plans.append(s.plan_writable(extra_tokens=1))
+                    except BaseException:
+                        self.pool.discard_plans(plans)
+                        raise
+                    pages = self.pool.materialize(plans)
+                    if sp is not None:
+                        sp.set_metadata(pages=pages)
+                # 2. stacked decode
+                with spans.span("engine.build"):
+                    last = [s.tokens[-1] for s in sessions]
+                    cache = self._build_cache(sessions)
+                    tokens = jnp.asarray(last, jnp.int32)
+                with spans.span("engine.dispatch"):
+                    decode = self._get_decode(len(sessions))
+                    logits, new_cache = decode(self.params, tokens, cache)
+                with spans.span("engine.absorb"):
+                    self._absorb_cache(sessions, new_cache, advance=True)
+            # 3. sampling with checkpointable rng, once the logits are here
+            with spans.span("engine.fetch"):
+                logits_np = np.asarray(logits, np.float32)
+            with spans.span("engine.sample"):
+                out = []
+                for i, s in enumerate(sessions):
+                    tok = self._sample(s, logits_np[i])
+                    s.tokens.append(tok)
+                    s.extras["last_logits"] = logits_np[i]
+                    out.append(tok)
         return out
 
     def generate(self, session: PagedSession, n_tokens: int) -> List[int]:
