@@ -619,16 +619,12 @@ class ChunkStore:
                 ids.append(self.put_digested(data, digest=digest, pad=pad))
         return tuple(ids)
 
-    def get_bytes(self, ids: tuple[int, ...]) -> bytes:
+    def _pieces(self, ids: tuple[int, ...]) -> List[_Buffer]:
+        """Each chunk's logical bytes (pad stripped), in order, uncopied."""
         if self.verify_reads:
             # correctness path: route every chunk through the verified get
-            out = []
-            for cid in ids:
-                data = self.get(cid)
-                pad = self.pad_of(cid)
-                out.append(data[: len(data) - pad] if pad else data)
-            return b"".join(out)
-        out = []
+            return [_unpadded(self.get(cid), self.pad_of(cid)) for cid in ids]
+        out: List[_Buffer] = []
         demoted: List[int] = []
         with self._lock:
             for i, cid in enumerate(ids):
@@ -641,18 +637,40 @@ class ChunkStore:
                     out.append(b"")         # placeholder; faulted in below
                     demoted.append(i)
                     continue
-                out.append(chunk.data[: len(chunk.data) - chunk.pad] if chunk.pad else chunk.data)
+                out.append(_unpadded(chunk.data, chunk.pad))
         for i in demoted:
             cid = ids[i]
             data = self.get(cid)            # promotion path: verified fault-in
-            pad = self.pad_of(cid)
-            out[i] = data[: len(data) - pad] if pad else data
-        return b"".join(out)
+            out[i] = _unpadded(data, self.pad_of(cid))
+        return out
+
+    def get_bytes(self, ids: tuple[int, ...]) -> bytes:
+        return b"".join(self._pieces(ids))
+
+    def read_into(self, ids: tuple[int, ...], out: np.ndarray) -> int:
+        """Copy the chunks' logical bytes, in order, into the flat uint8
+        ``out`` until it is full: each byte is copied once.  Returns the
+        number of bytes written."""
+        off, cap = 0, out.shape[0]
+        for piece in self._pieces(ids):
+            k = min(len(piece), cap - off)
+            if k > 0:
+                out[off : off + k] = np.frombuffer(piece, np.uint8, count=k)
+                off += k
+        return off
 
     def get_array(
         self, ids: tuple[int, ...], shape: tuple[int, ...], dtype: np.dtype
     ) -> np.ndarray:
-        raw = self.get_bytes(ids)
-        nbytes = int(np.dtype(dtype).itemsize * int(np.prod(shape, dtype=np.int64)))
-        flat = np.frombuffer(raw[:nbytes], dtype=dtype)
-        return flat.reshape(shape).copy()
+        """A fresh, writable array of ``shape`` and ``dtype`` from the chunks."""
+        out = np.empty(shape, np.dtype(dtype))
+        flat = out.reshape(-1).view(np.uint8)
+        if self.read_into(ids, flat) < flat.shape[0]:
+            raise ValueError(
+                f"chunks hold fewer than the {flat.shape[0]} bytes of a {shape} {dtype} array"
+            )
+        return out
+
+
+def _unpadded(data: bytes, pad: int) -> _Buffer:
+    return memoryview(data)[: len(data) - pad] if pad else data

@@ -420,29 +420,26 @@ class DeltaDumpPipeline:
         tensor of ``image`` and registers them as a diff/restore base
         (anchor-less — the grids own their memory).  Returns False when no
         tensor was rebuildable (nothing registered)."""
-        store = self.store
         views: Dict[str, ChunkedView] = {}
         for name, meta in image.entries.items():
             n = len(meta.chunk_ids)
             if n == 0:
                 continue
             try:
-                row_bytes = len(store.get(meta.chunk_ids[0]))
-                if row_bytes == 0 or not self._rows_match(meta, row_bytes):
-                    continue
-                grid = np.empty((n, row_bytes), np.uint8)
-                for i, cid in enumerate(meta.chunk_ids):
-                    grid[i] = np.frombuffer(store.get(cid), np.uint8)
+                grid = self._read_grid(meta)
             except Exception:
                 # a quarantined/corrupt chunk must not abort recovery: this
                 # tensor just misses the rebuilt anchor — the first dump
                 # against it pays the full path, which is correct, only slow
                 continue
+            if grid is None:
+                continue
+            grid.flags.writeable = False
             views[name] = ChunkedView(
                 shape=meta.shape,
                 dtype=meta.dtype,
                 nbytes=meta.nbytes,
-                chunk_bytes=row_bytes,
+                chunk_bytes=grid.shape[1],
                 n_chunks=n,
                 trailing_pad=meta.trailing_pad,
                 grid_fn=lambda g=grid: g,
@@ -1303,9 +1300,10 @@ class DeltaDumpPipeline:
 
         Tensors whose parent generation is still materialized are rebuilt as
         base grid + ``delta_apply`` scatter of only the chunks whose ids
-        differ from the parent's; everything else falls back to a full chunk
-        concatenation.  The rebuilt generation is registered so subsequent
-        restores (and dumps of its children) stay O(delta).
+        differ from the parent's; everything else is read from the store
+        into a fresh grid, one copy.  The rebuilt generation is registered
+        so subsequent restores (and dumps of its children) stay O(delta).
+        A payload tensor that is a view of its registered grid is read-only.
         """
         parent_rec = self.record_for(parent_image.image_id) if parent_image is not None else None
         try:
@@ -1387,35 +1385,34 @@ class DeltaDumpPipeline:
                                 base_grid, jnp.asarray(rows), jnp.asarray(idx)
                             )
                         grid_np = np.asarray(base_grid)
-                payload[name] = self._grid_to_array(grid_np, meta)
             else:
-                # no base generation: every chunk from the store
+                # no base generation: every chunk from the store, straight
+                # into the grid kept as the next base
                 with spans.span("deltacr.read", rows=len(meta.chunk_ids)):
-                    payload[name] = store.get_array(
-                        meta.chunk_ids, meta.shape, np.dtype(meta.dtype)
-                    )
-            # register the rebuilt tensor as a future base
-            row_bytes = (
-                base.chunk_bytes
-                if grid_np is not None
-                else len(store.get(meta.chunk_ids[0])) if meta.chunk_ids else 0
-            )
-            if row_bytes > 0 and payload[name].nbytes > 0:
-                if grid_np is not None:
-                    view = ChunkedView(
+                    grid_np = self._read_grid(meta)
+                    if grid_np is None:
+                        payload[name] = store.get_array(
+                            meta.chunk_ids, meta.shape, np.dtype(meta.dtype)
+                        )
+            if grid_np is None:
+                continue
+            # The payload and the next base share one grid.  Base grids are
+            # never written, and a payload that aliases one is read-only, so
+            # a restore_fn that writes copies first (CowArrayState counts a
+            # read-only array as shared).  The grid owns its bytes: chunk
+            # decrefs of a dropped checkpoint cannot reach it.
+            payload[name] = self._grid_to_array(grid_np, meta)
+            if meta.nbytes > 0:
+                with spans.span("deltacr.keep_base"):
+                    new_views[name] = ChunkedView(
                         shape=meta.shape,
                         dtype=meta.dtype,
-                        nbytes=payload[name].nbytes,
-                        chunk_bytes=row_bytes,
+                        nbytes=meta.nbytes,
+                        chunk_bytes=grid_np.shape[1],
                         n_chunks=grid_np.shape[0],
                         trailing_pad=meta.trailing_pad,
                         grid_fn=lambda g=grid_np: g,
                     )
-                else:
-                    with spans.span("deltacr.keep_base"):
-                        view = self._view_from_array(payload[name], meta, row_bytes)
-                if view is not None:
-                    new_views[name] = view
         return payload, new_views
 
     # ------------------------------------------------------- decode: tiled
@@ -1551,35 +1548,23 @@ class DeltaDumpPipeline:
         n = len(meta.chunk_ids)
         return n > 0 and n * row_bytes == meta.nbytes + meta.trailing_pad
 
-    @staticmethod
-    def _grid_to_array(grid: np.ndarray, meta: TensorMeta) -> np.ndarray:
-        buf = np.ascontiguousarray(grid).reshape(-1)[: meta.nbytes].copy()
-        return buf.view(np.dtype(meta.dtype)).reshape(meta.shape)
+    def _read_grid(self, meta: TensorMeta) -> Optional[np.ndarray]:
+        """The tensor's ``(n_chunks, row_bytes)`` grid filled from the store
+        with one copy, its tail zero-padded; None when the image's chunks
+        are not rows of one width."""
+        n = len(meta.chunk_ids)
+        row_bytes = self.store.size_of(meta.chunk_ids[0]) if n else 0
+        if row_bytes == 0 or not self._rows_match(meta, row_bytes):
+            return None
+        grid = np.empty((n, row_bytes), np.uint8)
+        flat = grid.reshape(-1)
+        if self.store.read_into(meta.chunk_ids, flat[: meta.nbytes]) < meta.nbytes:
+            raise ValueError(f"chunks hold fewer than the {meta.nbytes} bytes of the tensor")
+        flat[meta.nbytes :] = 0
+        return grid
 
     @staticmethod
-    def _view_from_array(
-        arr: np.ndarray, meta: TensorMeta, row_bytes: int
-    ) -> Optional[ChunkedView]:
-        n = len(meta.chunk_ids)
-        if n * row_bytes != meta.nbytes + meta.trailing_pad:
-            return None
-        # Eager copy, twice over: (a) the caller's restore_fn owns (and may
-        # mutate) the payload array after decode returns, so a lazy view
-        # would alias it; (b) a store-backed lazy rebuild would race
-        # drop_checkpoint's chunk decrefs on records still pinned by an
-        # in-flight dump.  Cost is bounded: at most max_generations decoded
-        # states stay resident, and MCTS re-injects templates so decode
-        # registrations are rare.
-        grid = np.zeros((n, row_bytes), np.uint8)
-        grid.reshape(-1)[: meta.nbytes] = (
-            np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
-        )
-        return ChunkedView(
-            shape=meta.shape,
-            dtype=meta.dtype,
-            nbytes=meta.nbytes,
-            chunk_bytes=row_bytes,
-            n_chunks=n,
-            trailing_pad=meta.trailing_pad,
-            grid_fn=lambda g=grid: g,
-        )
+    def _grid_to_array(grid: np.ndarray, meta: TensorMeta) -> np.ndarray:
+        """The tensor as a read-only view of the first ``nbytes`` of ``grid``."""
+        grid.flags.writeable = False
+        return grid.reshape(-1)[: meta.nbytes].view(np.dtype(meta.dtype)).reshape(meta.shape)

@@ -271,7 +271,9 @@ class CowArrayState:
     def _privatize(self, key: str, *, warm: bool = False) -> None:
         cell = self._shared[key]
         with cell.lock:
-            if cell.refs > 1:
+            # a read-only array is shared too: a decoded payload aliasing
+            # the pipeline's generation base, which is never written
+            if cell.refs > 1 or not self._arrays[key].flags.writeable:
                 cell.refs -= 1
                 self._arrays[key] = self._arrays[key].copy()
                 self._shared[key] = _SharedCell(refs=1)
@@ -1237,7 +1239,14 @@ class DeltaCR:
                     }
                 if sp is not None:
                     nbytes = sum(int(v.nbytes) for v in payload.values())
-                    sp.set_metadata(bytes=nbytes)
+                    # shared: tensors whose payload is a view of the grid the
+                    # pipeline kept as the next base (those, and only those,
+                    # are read-only host arrays)
+                    shared = sum(
+                        isinstance(v, np.ndarray) and not v.flags.writeable
+                        for v in payload.values()
+                    )
+                    sp.set_metadata(bytes=nbytes, shared=shared)
         finally:
             self.images.release(parent_ref)
             self.images.release(image_ref)

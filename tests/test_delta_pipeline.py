@@ -413,6 +413,110 @@ def test_paged_session_delta_chain():
     cr.shutdown()
 
 
+def _decode_spans(logdir):
+    """Args of every ``repro.deltacr.decode`` span in a captured trace."""
+    import glob
+
+    import jax
+
+    path = sorted(glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True))[-1]
+    data = jax.profiler.ProfileData.from_file(path)
+    return [
+        {k: v for k, v in e.stats}
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for ln in plane.lines
+        for e in ln.events
+        if e.name == "repro.deltacr.decode"
+    ]
+
+
+@pytest.mark.parametrize("over_parent", [False, True], ids=["no-base", "over-parent"])
+def test_paged_restore_payload_shares_the_kept_base(over_parent, tmp_path):
+    """A slow restore builds each KV grid once: the payload handed to
+    ``restore_fn`` is a read-only view of the grid registered as the image's
+    generation, bit-identical to the session at checkpoint time, and a child
+    dumped over that base equals the legacy path's image."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import DumpPolicy
+    from repro.serve import PagedSession
+
+    pool = _tiny_pool()
+    sess = PagedSession(pool)
+    sess.ensure_writable(extra_tokens=8)
+    sess.seq_len = 8
+    sess.tokens = list(range(1, 9))
+
+    def fill(session, pos, value):
+        payload = {}
+        for skey, tag in pool.attn_tags:
+            shape = pool.pools_k[skey][tag].shape
+            val = jnp.full((shape[0], shape[2], shape[3], shape[4]), value)
+            payload[f"{skey}/{tag}/k"] = np.asarray(val)
+            payload[f"{skey}/{tag}/v"] = np.asarray(-val)
+        pool.scatter_page(int(session.table[pos]), payload)
+
+    for pos in range(sess.n_pages):
+        fill(sess, pos, float(pos + 1))
+    seen = []
+
+    def restore_fn(p):
+        seen.append(p)
+        return PagedSession.restore_from_payload(pool, p)
+
+    cr = DeltaCR(restore_fn=restore_fn, policy=DumpPolicy(mode="delta"), async_warm=False)
+    want = {1: sess.dump_payload()}
+    cr.checkpoint(sess, 1, None)
+    sess.ensure_writable(extra_tokens=1)
+    sess.seq_len += 1
+    sess.tokens.append(9)
+    fill(sess, 2, 3.0)
+    want[2] = sess.dump_payload()
+    cr.checkpoint(sess, 2, 1)
+    cr.wait_dumps()
+    ckpt = 2 if over_parent else 1
+    image = cr.dump_future(ckpt).result()
+    cr.evict_template(ckpt)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        restored, path = cr.restore(ckpt)
+    assert path == "slow"
+    payload = seen[-1]
+    kv = [k for k in payload if k.startswith("kv/")]
+    assert kv and sorted(payload) == sorted(want[ckpt])
+    rec = cr.pipeline.record_for(image.image_id)
+    try:
+        for name, arr in payload.items():
+            assert not arr.flags.writeable, name
+            assert np.shares_memory(arr, rec.views[name].grid), name
+            np.testing.assert_array_equal(arr, want[ckpt][name])
+    finally:
+        cr.pipeline.release_record(rec)
+    (decode,) = _decode_spans(str(tmp_path / "trace"))
+    assert decode["shared"] == len(payload)
+
+    # a child of the restored session, dumped over the shared base
+    restored.ensure_writable(extra_tokens=4)
+    restored.seq_len += 4
+    restored.tokens += [10, 11, 12, 13]
+    fill(restored, restored.n_pages - 1, 7.0)
+    cr.checkpoint(restored, 3, ckpt)
+    legacy = DeltaCR(policy=DumpPolicy(mode="legacy"), async_warm=False)
+    legacy.checkpoint(restored, 3, None)
+    cr.wait_dumps()
+    legacy.wait_dumps()
+    child, _ = _payload_of(cr, 3)
+    ref, ref_image = _payload_of(legacy, 3)
+    assert cr.dump_future(3).result().mode == "delta" and ref_image.mode == "legacy"
+    assert sorted(child) == sorted(ref)
+    for name in ref:
+        np.testing.assert_array_equal(child[name], ref[name])
+    restored.release()
+    sess.release()
+    cr.shutdown()
+    legacy.shutdown()
+
+
 # ---------------------------------------------------------------------------
 # unit: digest_encode_array + ChunkedView layout invariants
 # ---------------------------------------------------------------------------

@@ -112,6 +112,39 @@ def test_drop_checkpoint_reclaims_storage():
     assert cr.store.stats.physical_bytes < before
 
 
+def test_read_only_payload_is_copied_on_first_write():
+    """A slow restore through the plain ``CowArrayState(p)`` restore_fn
+    hands out read-only views of the pipeline's generation base: the first
+    ``mutate`` copies (a CoW fault), and neither the base nor a later slow
+    restore of the same checkpoint sees the write."""
+    cr = DeltaCR(template_pool_size=1, restore_fn=lambda p: CowArrayState(p),
+                 chunk_bytes=64, async_warm=False)
+    s = _state(4, n=4096)
+    want = {k: s.get(k).copy() for k in s.keys()}
+    cr.checkpoint(s, 1, None)
+    cr.wait_dumps()
+    cr.evict_template(1)
+    r, path = cr.restore(1)
+    assert path == "slow"
+    cr.evict_template(1)                # r is the arrays' only holder now
+    image = cr.dump_future(1).result()
+    rec = cr.pipeline.record_for(image.image_id)
+    base = rec.views["a"].grid
+    cr.pipeline.release_record(rec)
+    assert np.shares_memory(r.get("a"), base)
+    before = base.copy()
+    faults = r.cow_faults
+    r.mutate("a", lambda a: a.__setitem__(0, 99.0))
+    assert r.cow_faults == faults + 1
+    assert r.get("a")[0] == 99.0
+    np.testing.assert_array_equal(base, before)
+    r2, path = cr.restore(1)
+    assert path == "slow"
+    for k in want:
+        np.testing.assert_array_equal(r2.get(k), want[k])
+    cr.shutdown()
+
+
 @settings(max_examples=20, deadline=None)
 @given(st.lists(st.sampled_from(["fork", "mutate", "release", "warm"]), min_size=1, max_size=30))
 def test_cow_state_isolation_property(ops):
