@@ -155,7 +155,12 @@ def load_json(path: str) -> Any:
 
 
 def load_module(path: str, name: Optional[str] = None):
-    """Import a benchmark file by path (names may hold dots and dashes)."""
+    """Import a benchmark file by path (names may hold dots and dashes).
+
+    The module is registered in ``sys.modules`` under its name: dataclasses
+    look up the module of a class they build."""
+    import sys
+
     mod_name = name or "bench_" + os.path.relpath(path, BENCH_DIR).replace(os.sep, "_").replace(
         ".", "_"
     ).replace("-", "_")
@@ -163,6 +168,7 @@ def load_module(path: str, name: Optional[str] = None):
     if spec is None or spec.loader is None:
         raise ImportError(path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod
     spec.loader.exec_module(mod)
     return mod
 

@@ -46,13 +46,14 @@ def _steps(run) -> List[List[int]]:
 def decode_mfu(run) -> Optional[float]:
     """Model FLOPs of every token decoded in the window, over the window,
     as a share of the chip's peak bf16 FLOP/s."""
-    from bench import model as bmodel
+    from bench.model import family
 
     steps = _steps(run)
     if not steps:
         return None
-    m = bmodel.dims(run.config)
-    flops = sum(bmodel.decode_flops(m, c) for ctx in steps for c in ctx)
+    fam = family(run.config, run.root)
+    m = fam.dims(run.config)
+    flops = sum(fam.decode_flops(m, c) for ctx in steps for c in ctx)
     return 100.0 * flops / run.window_s / run.peaks["bf16_flops_per_s"]
 
 
@@ -84,13 +85,15 @@ def roofline(run, kernel: str, calls: List[Dict[str, Any]]) -> Optional[float]:
 
 
 def attention_calls(run) -> List[Dict[str, Any]]:
-    """One paged-attention call per layer per decode step of the window."""
-    from bench import model as bmodel
+    """One paged-attention call per attention layer per decode step of the
+    window."""
+    from bench.model import family
 
-    m = bmodel.dims(run.config)
+    fam = family(run.config, run.root)
+    m = fam.dims(run.config)
     psz = int(run.traffic["page_size"])
     return [
         {"contexts": ctx, "page_size": psz, "heads": m.heads, "kv_heads": m.kv_heads,
          "head_dim": m.head_dim, "itemsize": 2}
-        for ctx in _steps(run) for _ in range(m.layers)
+        for ctx in _steps(run) for _ in range(fam.attention_layers(m))
     ]

@@ -91,9 +91,10 @@ def reference_check(run, *, control: bool = False):
     the gap of the token the float8 control puts first instead)."""
     import numpy as np
 
-    from bench import model as bmodel
+    from bench.model import family
 
-    ref = bmodel.Reference(bmodel.dims(run.config), control=control)
+    fam = family(run.config, run.root)
+    ref = fam.Reference(fam.dims(run.config), control=control)
     cut = int(run.traffic["prompt_tokens"]) - 1     # the prompt pass stops before its last token
     width = max(len(t) for t, _ in run.served) - cut
     width = -(-width // 128) * 128           # one compiled length for every sequence

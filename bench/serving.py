@@ -16,8 +16,8 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from bench import model as bmodel
 from bench.common import log
+from bench.model import family
 
 
 class System:
@@ -30,11 +30,12 @@ class System:
         from repro.serve import Engine, PagePool
 
         self.run = run
-        self.dims = bmodel.dims(run.config)
-        self.cfg = bmodel.program_config(run.config)
+        fam = family(run.config, run.root)
+        self.dims = fam.dims(run.config)
+        self.cfg = fam.program_config(run.config)
         self.model = Model(self.cfg)
         t = time.perf_counter()
-        self.params = bmodel.make_weights(self.dims, run.seed)
+        self.params = fam.make_weights(self.dims, run.seed)
         want = jax.tree.map(lambda a: (a.shape, str(a.dtype)), self.model.param_shapes())
         got = jax.tree.map(lambda a: (a.shape, str(a.dtype)), self.params)
         if want != got:

@@ -1,14 +1,17 @@
 """What a checkpoint must give back: digests of a sandbox's state.
 
 At every checkpoint the harness records the sandbox's state (tokens,
-sequence length, sampling rng extras, the DeltaFS view, and a checksum of
-the session's live KV pages computed on the device); at every rollback it
-records the restored state the same way.  After the window each rollback
-is compared with the record of the checkpoint it rolled back to.  The KV
-checksum is dispatched asynchronously and read only after the window: the
-window pays no device round trip for it, but its device time (one read of
-the session's live pages) runs in the window and shows in the trace under
-the ``_kv_checksum`` program, and its host time under the ``check`` span.
+sequence length, sampling rng extras, the DeltaFS view, a checksum of the
+session's live KV pages computed on the device, and, where the model keeps
+recurrent state per session, a checksum of that state computed the same
+way); at every rollback it records the restored state the same way.  After
+the window each rollback is compared with the record of the checkpoint it
+rolled back to.  The checksums are dispatched asynchronously and read only
+after the window: the window pays no device round trip for them, but their
+device time (one read of the session's live pages, and of its recurrent
+state) runs in the window and shows in the trace under the ``_kv_checksum``
+and ``_recurrent_checksum`` programs, and their host time under the
+``check`` span.
 """
 from __future__ import annotations
 
@@ -20,7 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 
 RNG_KEYS = ("rng_seed", "rng_counter", "temperature")
+DEVICE_FIELDS = ("kv", "recurrent")     # checksums left on the device until the window closed
 PAGE_BUCKET = 16         # live pages are read in multiples of this: few compiled shapes
+
+
+def _bits(a: jax.Array) -> jax.Array:
+    """The bits of each element of ``a`` (2 or 4 bytes), as uint32."""
+    uint = {2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize]
+    return jax.lax.bitcast_convert_type(a, uint).astype(jnp.uint32)
 
 
 @jax.jit
@@ -33,7 +43,7 @@ def _kv_checksum(pools: Tuple[jax.Array, ...], table: jax.Array, seq_len: jax.Ar
     acc = jnp.uint32(0)
     for i, pool in enumerate(pools):
         g = pool[:, table]                                   # (L, maxp, psz, KVH, Hd)
-        bits = jax.lax.bitcast_convert_type(g, jnp.uint16).astype(jnp.uint32)
+        bits = _bits(g)
         L, P, S = g.shape[:3]
         pos = (jnp.arange(P)[:, None] * S + jnp.arange(S)[None, :]).astype(jnp.uint32)
         live = pos < seq_len.astype(jnp.uint32)
@@ -46,6 +56,36 @@ def _kv_checksum(pools: Tuple[jax.Array, ...], table: jax.Array, seq_len: jax.Ar
         term = jnp.where(live[None, :, :, None, None], bits * mult, jnp.uint32(0))
         acc = acc + jnp.sum(term, dtype=jnp.uint32)
     return acc
+
+
+@jax.jit
+def _recurrent_checksum(leaves: Tuple[jax.Array, ...]) -> jax.Array:
+    """Position-weighted uint32 sum of the bits of every element of
+    ``leaves`` (arrays of 2 or 4 bytes an element).  A change of
+    any one element changes the sum: its weight is odd."""
+    acc = jnp.uint32(0)
+    for i, a in enumerate(leaves):
+        bits = _bits(a).reshape(-1)
+        idx = jnp.arange(bits.size, dtype=jnp.uint32)
+        mult = (idx + jnp.uint32(i) * jnp.uint32(0x9E3779B9)) * jnp.uint32(2654435761) | jnp.uint32(1)
+        acc = acc + jnp.sum(bits * mult, dtype=jnp.uint32)
+    return acc
+
+
+def recurrent_extras(session) -> Dict[str, Any]:
+    """The session's recurrent state: the ``stage<i>/<tag>`` extras that
+    ``Engine._absorb_cache`` writes, each a dict of arrays (``conv``,
+    ``ssm`` for a Mamba layer)."""
+    return {k: v for k, v in session.extras.items() if k.startswith("stage") and isinstance(v, dict)}
+
+
+def recurrent_checksum(session):
+    """Device-side checksum of a session's recurrent state (async), or None
+    for a session that keeps none."""
+    state = recurrent_extras(session)
+    if not state:
+        return None
+    return _recurrent_checksum(tuple(jax.tree.leaves([state[k] for k in sorted(state)])))
 
 
 def kv_checksum(session) -> jax.Array:
@@ -80,7 +120,7 @@ def fs_digest(fs) -> str:
 
 
 def snapshot(sandbox) -> Dict[str, Any]:
-    """State record of a sandbox (the KV checksum still on the device)."""
+    """State record of a sandbox (its checksums still on the device)."""
     sess = sandbox.proc
     toks = np.asarray(sess.tokens, np.int64)
     rec = {
@@ -90,6 +130,9 @@ def snapshot(sandbox) -> Dict[str, Any]:
         "fs": fs_digest(sandbox.fs),
         "kv": kv_checksum(sess),
     }
+    recurrent = recurrent_checksum(sess)
+    if recurrent is not None:
+        rec["recurrent"] = recurrent
     for k in RNG_KEYS:
         if k in sess.extras:
             rec[k] = np.asarray(sess.extras[k]).tolist()
@@ -112,9 +155,11 @@ class StateLog:
     def mismatches(self) -> Tuple[int, int, List[str]]:
         """(rollbacks compared, rollbacks that differ, first differences)."""
         compared, bad, notes = 0, 0, []
-        kv = jax.device_get(
-            [(self.at_checkpoint[k]["kv"], r["kv"]) for k, _, r in self.rollbacks if k in self.at_checkpoint]
-        )
+        pairs = [
+            {f: (self.at_checkpoint[k].get(f), r.get(f)) for f in DEVICE_FIELDS}
+            for k, _, r in self.rollbacks if k in self.at_checkpoint
+        ]
+        pairs = jax.device_get(pairs)
         i = 0
         for key, path, got in self.rollbacks:
             want = self.at_checkpoint.get(key)
@@ -122,12 +167,11 @@ class StateLog:
                 bad += 1
                 notes.append(f"rollback to {key} ({path}): no record of that checkpoint")
                 continue
-            kv_want, kv_got = kv[i]
+            sums = pairs[i]
             i += 1
             compared += 1
-            diff = [f for f in want if f != "kv" and want[f] != got.get(f)]
-            if int(kv_want) != int(kv_got):
-                diff.append("kv")
+            diff = [f for f in want if f not in DEVICE_FIELDS and want[f] != got.get(f)]
+            diff += [f for f in DEVICE_FIELDS if sums[f][0] != sums[f][1]]
             if diff:
                 bad += 1
                 if len(notes) < 5:

@@ -81,6 +81,7 @@ def test_config_file(config):
     data = load_json(os.path.join(ROOT, config["file"]))
     assert data["name"] == config["name"] and data["source"] == config["source"]
     assert data["reduced"] == config["reduced"]
+    assert os.path.isfile(os.path.join(ROOT, "bench", "models", data["model"] + ".py"))
     assert any(w["config"] == config["name"] for w in MANIFEST["workloads"])
 
 

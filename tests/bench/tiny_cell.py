@@ -19,19 +19,33 @@ TINY_TRAFFIC = {
 }
 
 
-def add_tiny_cell(dst: str, source_cell: str, **traffic_over) -> str:
+def tiny_config(config_file: str) -> dict:
+    """The configuration in ``config_file`` (relative to the checkout) at
+    the tiny sizes of ``TINY_MODEL``."""
+    cfg = json.load(open(os.path.join(ROOT, config_file)))
+    cfg.update(name="tiny", source="test", **TINY_MODEL)
+    if cfg.get("rope_scaling"):                        # M-RoPE bands over the tiny head
+        cfg["rope_scaling"] = dict(cfg["rope_scaling"], mrope_section=[2, 3, 3])
+    return cfg
+
+
+def add_tiny_cell(dst: str, source_cell: str, *, family=None, **traffic_over) -> str:
     """Copy BENCHMARK.json and bench/ into ``dst`` and add a cell named
     ``tiny.<mix>`` whose configuration, mix and limits are new files, and
-    whose metrics are those of ``source_cell``.  Returns the cell name."""
+    whose metrics are those of ``source_cell``.  ``family``, a pair (name,
+    source), also adds ``bench/models/<name>.py`` and has the configuration
+    name it.  Returns the cell name."""
     shutil.copytree(os.path.join(ROOT, "bench"), os.path.join(dst, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__"))
     manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     src = next(w for w in manifest["workloads"] if w["name"] == source_cell)
     src_cfg = next(c for c in manifest["configs"] if c["name"] == src["config"])
-    cfg = json.load(open(os.path.join(ROOT, src_cfg["file"])))
-    cfg.update(name="tiny", source="test", **TINY_MODEL)
-    if cfg.get("rope_scaling"):                        # M-RoPE bands over the tiny head
-        cfg["rope_scaling"] = dict(cfg["rope_scaling"], mrope_section=[2, 3, 3])
+    cfg = tiny_config(src_cfg["file"])
+    if family is not None:
+        fam_name, fam_source = family
+        with open(os.path.join(dst, "bench", "models", fam_name + ".py"), "w") as f:
+            f.write(fam_source)
+        cfg["model"] = fam_name
     json.dump(cfg, open(os.path.join(dst, "bench", "configs", "tiny.json"), "w"))
     traffic = json.load(open(os.path.join(ROOT, "bench", "traffic", src["traffic"] + ".json")))
     traffic.update(TINY_TRAFFIC[traffic["driver"]], **traffic_over)
